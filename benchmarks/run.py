@@ -49,6 +49,9 @@ SUITES = {
 
 def main() -> None:
     from benchmarks import common
+    from repro.compile_cache import enable_compile_cache
+
+    print(f"# compile cache: {enable_compile_cache()}")
 
     want = sys.argv[1:] or [k for k in SUITES if k != "serve_obs"]
     print("name,us_per_call,derived")

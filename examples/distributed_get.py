@@ -7,24 +7,26 @@ Bourbon read path (all-gather probes -> learned local lookup -> masked psum).
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.datasets import make_dataset
 from repro.core.distributed import (DistStoreConfig, build_dist_get,
                                     build_dist_state)
-from repro.core.jaxcompat import make_mesh, set_mesh
 
 keys = make_dataset("ar", 1 << 16, seed=2)
 vptrs = np.arange(keys.shape[0], dtype=np.int64)
 cfg = DistStoreConfig(n_keys=keys.shape[0], probe_batch=1 << 12)
 
-mesh = make_mesh((jax.device_count(),), ("data",), axis_type="Explicit")
-state = {k: jnp.asarray(v) for k, v in
-         build_dist_state(keys, vptrs, mesh.size, cfg).items()}
+mesh = jax.make_mesh((jax.device_count(),), ("data",),
+                     axis_types=(AxisType.Explicit,))
+# each shard's row on its own device
+state = jax.device_put(build_dist_state(keys, vptrs, mesh.size, cfg),
+                       NamedSharding(mesh, P("data")))
 fn = build_dist_get(mesh, cfg)
 
 rng = np.random.default_rng(0)
 probes = jnp.asarray(rng.choice(keys, cfg.probe_batch))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     found, vp = fn(state, probes)
 print(f"devices={mesh.size} probes={cfg.probe_batch} "
       f"hit_rate={float(jnp.mean(found)):.3f}")

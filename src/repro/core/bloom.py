@@ -11,11 +11,12 @@ wants.
 
 from __future__ import annotations
 
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 __all__ = ["bloom_build_np", "bloom_probe_np", "bloom_probe_hashed_np",
-           "bloom_probe_ref",
+           "bloom_probe_ref", "bloom_hashes", "bloom_word_bit",
            "bloom_words", "DEFAULT_BITS_PER_KEY"]
 
 DEFAULT_BITS_PER_KEY = 10
@@ -77,6 +78,44 @@ def bloom_probe_hashed_np(bits: np.ndarray, h1: np.ndarray, h2: np.ndarray,
     return maybe
 
 
+def bloom_hashes(probes: jnp.ndarray, k_hashes: int) -> jnp.ndarray:
+    """(k_hashes, *probes.shape) uint64 hash values h1 + i*h2 of each key
+    (the device twin of ``_hash2_np``)."""
+    kk = probes.astype(jnp.uint64)
+    h1 = kk * jnp.uint64(_MIX1)
+    h1 = h1 ^ (h1 >> jnp.uint64(29))
+    h2 = (kk * jnp.uint64(_MIX2)) | jnp.uint64(1)
+    h2 = h2 ^ (h2 >> jnp.uint64(31))
+    i = jnp.arange(k_hashes, dtype=jnp.uint64).reshape((-1,) + (1,) * kk.ndim)
+    return h1 + i * h2
+
+
+def bloom_word_bit(x: jnp.ndarray, n_words: jnp.ndarray):
+    """Word index and bit index of hash value ``x`` (uint64) in a filter of
+    ``n_words`` 64-bit words — the position ``x mod (64 * n_words)`` as
+    (``(x >> 6) mod n_words``, ``x mod 64``), the same bits the host build
+    sets.
+
+    The TPU compiler emulates 64-bit division, and one uint64 ``%`` costs
+    it tens of seconds of compile time.  So the remainder is a restoring
+    division over the 58 bits of ``x >> 6``, one bit per loop step, in
+    32-bit lanes: with ``n_words < 2**31`` the running remainder stays
+    below ``2**32``.  Stack every hash of a probe into ``x`` so that one
+    loop serves them all."""
+    y = x >> jnp.uint64(6)
+    n = jnp.asarray(n_words).astype(jnp.uint32)
+
+    def step(j, r):
+        b = (y >> (57 - j).astype(jnp.uint64)) & jnp.uint64(1)
+        r = (r << 1) | b.astype(jnp.uint32)
+        return jnp.where(r >= n, r - n, r)
+
+    r = jax.lax.fori_loop(
+        0, 58, step, jnp.zeros(jnp.broadcast_shapes(x.shape, n.shape),
+                               jnp.uint32))
+    return r.astype(jnp.int32), x & jnp.uint64(63)
+
+
 def bloom_probe_ref(bits: jnp.ndarray, probes: jnp.ndarray, k_hashes: int = 7,
                     n_words=None) -> jnp.ndarray:
     """Pure-jnp batched probe.
@@ -89,21 +128,11 @@ def bloom_probe_ref(bits: jnp.ndarray, probes: jnp.ndarray, k_hashes: int = 7,
     """
     if n_words is None:
         n_words = bits.shape[-1]
-    m = (jnp.asarray(n_words).astype(jnp.uint64) * jnp.uint64(64))
-    m = jnp.broadcast_to(m, probes.shape)
-    kk = probes.astype(jnp.uint64)
-    h1 = kk * jnp.uint64(0x9E3779B97F4A7C15)
-    h1 = h1 ^ (h1 >> jnp.uint64(29))
-    h2 = (kk * jnp.uint64(0xC2B2AE3D27D4EB4F)) | jnp.uint64(1)
-    h2 = h2 ^ (h2 >> jnp.uint64(31))
-    maybe = jnp.ones(probes.shape, bool)
-    for i in range(k_hashes):
-        pos = (h1 + jnp.uint64(i) * h2) % m
-        widx = (pos >> jnp.uint64(6)).astype(jnp.int32)
-        if bits.ndim == 1:
-            word = bits[widx]
-        else:
-            word = jnp.take_along_axis(bits, widx[..., None], axis=-1)[..., 0]
-        bit = (word >> (pos & jnp.uint64(63))) & jnp.uint64(1)
-        maybe = maybe & (bit == jnp.uint64(1))
-    return maybe
+    widx, b = bloom_word_bit(bloom_hashes(probes, k_hashes),
+                             jnp.broadcast_to(jnp.asarray(n_words),
+                                              probes.shape))   # (k, B)
+    if bits.ndim == 1:
+        word = bits[widx]
+    else:
+        word = bits[jnp.arange(probes.shape[0])[None, :], widx]
+    return jnp.all((word >> b) & jnp.uint64(1) == jnp.uint64(1), axis=0)

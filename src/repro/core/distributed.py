@@ -29,7 +29,6 @@ import numpy as np
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .jaxcompat import shard_map
 from .plr import greedy_plr_np
 
 __all__ = ["DistStoreConfig", "build_dist_state", "build_dist_state_from_shards",
@@ -263,9 +262,15 @@ def build_dist_get(mesh, cfg: DistStoreConfig, seg_search: str = "bisect",
         found = hit.astype(jnp.int8)
         vsum = jnp.where(hit, vptr, 0)
         if combine == "reduce_scatter":
+            # the TPU compiler has no 64-bit reduce-scatter: combine the
+            # vptrs as (B, 2) uint32 halves.  Each probe has exactly one
+            # owner and every other shard contributes zeros, so each half
+            # sums with no carry and the bitcast back is exact
+            halves = jax.lax.bitcast_convert_type(vsum, jnp.uint32)
             for a in reversed(ax):
                 found = jax.lax.psum_scatter(found, a, tiled=True)
-                vsum = jax.lax.psum_scatter(vsum, a, tiled=True)
+                halves = jax.lax.psum_scatter(halves, a, tiled=True)
+            vsum = jax.lax.bitcast_convert_type(halves, jnp.int64)
         else:
             for a in ax:
                 found = jax.lax.psum(found, a)
@@ -273,7 +278,7 @@ def build_dist_get(mesh, cfg: DistStoreConfig, seg_search: str = "bisect",
         return found > 0, jnp.where(found > 0, vsum, -1)
 
     out_spec = probe_spec if combine == "reduce_scatter" else P()
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=({k: state_spec for k in state_keys}, probe_spec),
         out_specs=(out_spec, out_spec),

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .bloom import bloom_probe_ref
+from .bloom import bloom_hashes, bloom_word_bit
 from .lsm import LSMTree, N_LEVELS
 from .sstable import BLOCK_RECORDS
 
@@ -241,21 +241,10 @@ def bloom_probe_rows(bits: jnp.ndarray, nwords: jnp.ndarray, rows: jnp.ndarray,
 
     Gathers only the k addressed words per probe (never whole filter rows —
     that would move B*W bytes per call)."""
-    m = nwords[rows].astype(jnp.uint64) * jnp.uint64(64)
-    kk = probes.astype(jnp.uint64)
-    h1 = kk * jnp.uint64(0x9E3779B97F4A7C15)
-    h1 = h1 ^ (h1 >> jnp.uint64(29))
-    h2 = (kk * jnp.uint64(0xC2B2AE3D27D4EB4F)) | jnp.uint64(1)
-    h2 = h2 ^ (h2 >> jnp.uint64(31))
-    maybe = jnp.ones(probes.shape, bool)
-    W = bits.shape[-1]
-    for i in range(k_hashes):
-        pos = (h1 + jnp.uint64(i) * h2) % m
-        widx = jnp.clip((pos >> jnp.uint64(6)).astype(jnp.int32), 0, W - 1)
-        word = bits[rows, widx]
-        bit = (word >> (pos & jnp.uint64(63))) & jnp.uint64(1)
-        maybe = maybe & (bit == jnp.uint64(1))
-    return maybe
+    widx, b = bloom_word_bit(bloom_hashes(probes, k_hashes),
+                             nwords[rows])                    # (k, B)
+    word = bits[rows[None, :], jnp.clip(widx, 0, bits.shape[-1] - 1)]
+    return jnp.all((word >> b) & jnp.uint64(1) == jnp.uint64(1), axis=0)
 
 
 # ----------------------------------------------------------------------------

@@ -43,6 +43,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.cba import CBAConfig, MaintenanceConfig
 from repro.core.clock import CostModel
@@ -51,7 +52,6 @@ from repro.core.distributed import (DistStoreConfig, build_dist_get,
                                     dist_get_local, next_pow2)
 from repro.core.engine import EngineConfig
 from repro.core.filters import FilterConfig, build_level_filter
-from repro.core.jaxcompat import make_mesh, set_mesh
 from repro.core.lsm import LSMConfig
 from repro.core.plr import greedy_plr_np
 from repro.core.store import BourbonStore, StoreConfig
@@ -310,11 +310,8 @@ class ShardedStore:
         if mesh == "auto":
             mesh = None
             if len(jax.devices()) >= n_shards:
-                try:
-                    mesh = make_mesh((n_shards,), ("shard",),
-                                     axis_type="Explicit")
-                except Exception:
-                    mesh = None
+                mesh = jax.make_mesh((n_shards,), ("shard",),
+                                     axis_types=(AxisType.Explicit,))
         return cls(path, splits, shards, delta, mesh)
 
     def close(self) -> None:
@@ -462,7 +459,13 @@ class ShardedStore:
             state_np = build_dist_state_from_shards(
                 self._snaps, self.delta, models=self._snap_models,
                 filters=self._snap_filters if fc.enabled else None)
-            self._state = {k: jnp.asarray(v) for k, v in state_np.items()}
+            # on the mesh each shard's row lives on its own device; the
+            # fused one-device program reads every row from one device
+            place = (NamedSharding(self._mesh,
+                                   P(tuple(self._mesh.axis_names)))
+                     if self._mesh is not None else None)
+            self._state = {k: jax.device_put(v, place)
+                           for k, v in state_np.items()}
             self._state_epochs = epochs
             self.state_epoch += 1
         return self._state
@@ -491,7 +494,7 @@ class ShardedStore:
             pad = -(-pad // self.n_shards) * self.n_shards
             buf = np.full(pad, _PAD_PROBE, np.int64)
             buf[:n] = probes
-            with set_mesh(self._mesh):
+            with jax.set_mesh(self._mesh):
                 f, v = self._get_fn(state, jnp.asarray(buf))
             return f, v
         # host fallback: the same shard kernel, all shard rows fused into

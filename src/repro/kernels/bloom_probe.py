@@ -51,7 +51,7 @@ def _bloom_kernel(nw_ref, bits_ref, probes_ref, out_ref, *, k_hashes: int):
 
 @partial(jax.jit, static_argnames=("k_hashes", "block_b", "interpret"))
 def bloom_probe_pallas(bits, probes, n_words, k_hashes: int = 7,
-                       block_b: int = 256, interpret: bool = True):
+                       block_b: int = 256, *, interpret: bool):
     """Matches core.bloom.bloom_probe_ref for a shared (W,) filter.
 
     Arbitrary batch sizes are supported: the probe batch is padded up to a
@@ -102,7 +102,7 @@ def _bloom_stack_kernel(nw_ref, bits_ref, probes_ref, out_ref, *,
 
 @partial(jax.jit, static_argnames=("k_hashes", "block_b", "interpret"))
 def bloom_probe_stack_pallas(bits, n_words, probes, k_hashes: int = 7,
-                             block_b: int = 256, interpret: bool = True):
+                             block_b: int = 256, *, interpret: bool):
     """Probe the whole batch against a stacked (L, W) filter plane.
 
     bits: (L, W) uint64 — per-level filter words, width-padded to a common W.

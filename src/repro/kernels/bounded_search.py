@@ -46,7 +46,7 @@ def _bounded_kernel(n_ref, pos_ref, probes_ref, keys_ref, idx_ref, found_ref,
 
 @partial(jax.jit, static_argnames=("delta", "block_b", "interpret"))
 def bounded_search_pallas(keys, pos, probes, n, delta: int = 8,
-                          block_b: int = 256, interpret: bool = True):
+                          block_b: int = 256, *, interpret: bool):
     """Matches kernels.ref.bounded_search_ref (idx may differ only when the
     same key appears at the window edge twice — keys are unique, so exact)."""
     B = probes.shape[0]

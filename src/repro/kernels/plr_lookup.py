@@ -54,7 +54,7 @@ def _plr_kernel(nseg_ref, nmax_ref, starts_ref, slopes_ref, icepts_ref,
 
 @partial(jax.jit, static_argnames=("block_b", "interpret"))
 def plr_lookup_pallas(starts, slopes, icepts, nseg, probes, n_max,
-                      block_b: int = 256, interpret: bool = True):
+                      block_b: int = 256, *, interpret: bool):
     """Matches kernels.ref.plr_lookup_ref exactly."""
     B = probes.shape[0]
     S = starts.shape[0]

@@ -89,21 +89,13 @@ def bloom_probe_stack_ref(bits: jnp.ndarray, n_words: jnp.ndarray,
     ``n_words[l] == 0`` marks a level with no filter (all-True row); the
     hash modulus is each level's build-time word count, never the padded W.
     """
+    from repro.core.bloom import bloom_hashes, bloom_word_bit
     L, W = bits.shape
     nw = jnp.asarray(n_words, jnp.int32)
-    m = jnp.maximum(nw, 1).astype(jnp.uint64)[:, None] * jnp.uint64(64)
-    kk = probes.astype(jnp.uint64)
-    h1 = kk * jnp.uint64(0x9E3779B97F4A7C15)
-    h1 = h1 ^ (h1 >> jnp.uint64(29))
-    h2 = (kk * jnp.uint64(0xC2B2AE3D27D4EB4F)) | jnp.uint64(1)
-    h2 = h2 ^ (h2 >> jnp.uint64(31))
-    maybe = jnp.ones((L, probes.shape[0]), bool)
-    for i in range(k_hashes):
-        pos = (h1 + jnp.uint64(i) * h2)[None, :] % m
-        widx = jnp.clip((pos >> jnp.uint64(6)).astype(jnp.int32), 0, W - 1)
-        word = jnp.take_along_axis(bits, widx, axis=1)
-        bit = (word >> (pos & jnp.uint64(63))) & jnp.uint64(1)
-        maybe = maybe & (bit == jnp.uint64(1))
+    widx, b = bloom_word_bit(bloom_hashes(probes, k_hashes)[:, None, :],
+                             jnp.maximum(nw, 1)[:, None])     # (k, L, B)
+    word = bits[jnp.arange(L)[None, :, None], jnp.clip(widx, 0, W - 1)]
+    maybe = jnp.all((word >> b) & jnp.uint64(1) == jnp.uint64(1), axis=0)
     return maybe | (nw == 0)[:, None]
 
 

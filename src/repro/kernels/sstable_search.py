@@ -84,7 +84,7 @@ def _search_kernel(meta_ref, fences_ref, probes_ref, keys_ref, idx_ref,
 @partial(jax.jit, static_argnames=("block_records", "block_b", "interpret"))
 def sstable_search_pallas(fences, keys, probes, n_blocks, n,
                           block_records: int = 256, block_b: int = 256,
-                          interpret: bool = True):
+                          *, interpret: bool):
     """Matches kernels.ref.sstable_search_ref on found probes."""
     B = probes.shape[0]
     NB = fences.shape[0]

@@ -85,10 +85,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     res["scan_param_fsdp"] = scan_param_fsdp
     res["grad_accum_dtype"] = grad_accum_dtype
 
-    # function-local on purpose: jaxcompat imports jax, and this
-    # module's --all parent must never pay jax init (see header)
-    from repro.core.jaxcompat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             step = build_train_step(cfg, tcfg, rules, mesh)
             pspec, bspec = input_specs(cfg, shape, mesh, rules)
@@ -169,10 +166,7 @@ def run_store_cell(*, multi_pod: bool = False, n_keys: int = 1 << 30,
            "n_keys": n_keys, "probe_batch": probe_batch,
            "seg_search": seg_search, "combine": combine}
     t0 = time.time()
-    # function-local on purpose: jaxcompat imports jax, and this
-    # module's --all parent must never pay jax init (see header)
-    from repro.core.jaxcompat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         specs = dist_state_specs(mesh, cfg)
         probes = jax.ShapeDtypeStruct(
             (probe_batch,), jnp.int64,

@@ -8,7 +8,7 @@ axis crossing DCI.
 
 from __future__ import annotations
 
-from repro.core.jaxcompat import make_mesh
+import jax
 
 __all__ = ["make_production_mesh", "batch_axes", "HW"]
 
@@ -16,7 +16,8 @@ __all__ = ["make_production_mesh", "batch_axes", "HW"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_type="Auto")
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def batch_axes(mesh) -> tuple:

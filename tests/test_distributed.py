@@ -19,7 +19,6 @@ from repro.core.distributed import (KEY_SENTINEL, DistStoreConfig,
                                     build_dist_state_from_shards,
                                     dist_get_local)
 from repro.core.engine import EngineConfig
-from repro.core.jaxcompat import make_mesh, set_mesh
 from repro.distributed import ShardedConfig, ShardedStore, load_shard_snapshot
 
 
@@ -48,7 +47,8 @@ def test_dist_get_shardmap_single_device():
     keys = make_dataset("ar", 2048, seed=5)
     vptrs = np.arange(2048, dtype=np.int64)
     cfg = DistStoreConfig(n_keys=2048, probe_batch=128)
-    mesh = make_mesh((1,), ("data",), axis_type="Explicit")
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Explicit,))
     state_np = build_dist_state(keys, vptrs, n_shards=1, cfg=cfg)
     state = {k: jnp.asarray(v) for k, v in state_np.items()}
     fn = build_dist_get(mesh, cfg)
@@ -56,7 +56,7 @@ def test_dist_get_shardmap_single_device():
     pos = rng.choice(keys, 64)
     neg = pos + 1
     probes = jnp.asarray(np.concatenate([pos, neg]))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         found, vptr = fn(state, probes)
     found = np.asarray(found)
     assert found[:64].all()

@@ -16,7 +16,6 @@ import numpy as np
 import pytest
 
 from repro.checkpoint.ckpt import AsyncSaver, latest_step, restore, save
-from repro.core.jaxcompat import make_mesh, shard_map
 from repro.data.pipeline import DataConfig, TokenDataset, synthetic_tokens
 from repro.launch.elastic import ElasticController, shrink_plan
 from repro.optim import compressed_psum, dequantize_int8, quantize_int8
@@ -144,15 +143,16 @@ def test_compressed_psum_matches_fp32():
     devs = jax.devices()
     if len(devs) < 1:
         pytest.skip("no devices")
-    mesh = make_mesh((1,), ("pod",), axis_type="Explicit")
+    mesh = jax.make_mesh((1,), ("pod",),
+                         axis_types=(jax.sharding.AxisType.Explicit,))
     x = jnp.asarray(np.random.default_rng(1).standard_normal((4, 256)),
                     jnp.float32)
 
     def f(xs):
         return compressed_psum(xs, "pod")
 
-    out = shard_map(f, mesh=mesh, in_specs=P("pod"),
-                    out_specs=P("pod"))(x)
+    out = jax.shard_map(f, mesh=mesh, in_specs=P("pod"),
+                        out_specs=P("pod"))(x)
     # single shard: psum over 1 device = identity (quantize/dequant error only)
     err = np.abs(np.asarray(out) - np.asarray(x)).max()
     assert err < np.abs(np.asarray(x)).max() / 127 + 1e-5
