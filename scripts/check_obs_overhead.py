@@ -17,8 +17,9 @@ from __future__ import annotations
 import json
 import sys
 
-# canonical stage set, kept in lockstep with repro.obs.READ_STAGES (the
-# script must stay runnable without PYTHONPATH=src, so no import)
+# the read-path stages, the first eight of repro.obs.READ_STAGES (the
+# script must stay runnable without PYTHONPATH=src, so no import); the
+# gated arms only read, so the write-side stages have nothing to observe
 STAGES = ("admission", "coalesce", "cache_probe", "filter_probe", "dispatch",
           "compute", "resolve", "value_fetch")
 

@@ -41,9 +41,11 @@ from .core import Finding, Rule, SourceFile, dotted, walk_functions
 
 # fallbacks when the obs sources are unavailable (fixture tests)
 FALLBACK_PREFIXES = ("server", "cache", "store", "engine", "fleet", "obs")
-FALLBACK_LABELS = ("shard", "level", "stage", "path", "key", "index")
-FALLBACK_STAGES = ("admission", "coalesce", "cache_probe", "dispatch",
-                   "compute", "resolve", "value_fetch")
+FALLBACK_LABELS = ("shard", "level", "stage", "path", "key", "index",
+                   "generation")
+FALLBACK_STAGES = ("admission", "coalesce", "cache_probe", "filter_probe",
+                   "dispatch", "compute", "resolve", "value_fetch", "retire",
+                   "write_apply", "wal_sync", "maintenance", "complete")
 FALLBACK_SPANS = ("request", "queue_wait", "batch", "dispatch",
                   "shard_probe", "device_compute", "io_task",
                   "value_fetch", "write_apply", "wal_append",
@@ -109,13 +111,8 @@ def _tables_from_readme(path: str):
                                               text)))
     labels = tuple(dict.fromkeys(re.findall(r"\|\s*`([a-z_]+)=`\s*\|",
                                             text)))
-    stages = None
-    m = re.search(r"READ_STAGES.*?\n((?:\|.*\n)+)", text)
-    if m:
-        rows = re.findall(r"^\|\s*`([a-z_]+)`\s*\|", m.group(1), re.M)
-        if rows:
-            stages = tuple(rows)
-    return prefixes or None, labels or None, stages
+    return (prefixes or None, labels or None,
+            _marked_table_from_readme(path, "READ_STAGES"))
 
 
 class ObsDriftRule(Rule):

@@ -38,11 +38,13 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import time
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core.cba import CBAConfig, MaintenanceConfig
@@ -57,7 +59,7 @@ from repro.core.plr import greedy_plr_np
 from repro.core.store import BourbonStore, StoreConfig
 from repro.io import ValueFetch, wait_all
 from repro.kernels.ref import bloom_probe_stack_ref
-from repro.obs import NULL_CTRACE, NULL_HANDLE, publish_stats
+from repro.obs import NULL_CTRACE, NULL_HANDLE, SPAN_PREFIX, publish_stats
 from repro.storage.format import fsync_dir, sst_path
 from repro.storage.manifest import read_manifest
 from repro.storage.sstable_io import load_sstable
@@ -218,6 +220,8 @@ class ShardedStore:
         self._state = None
         self._state_epochs = None
         self.state_epoch = 0          # bumps whenever the device state refreshes
+        self.state_rebuild_us = 0.0   # host time of those refreshes
+        self.state_upload_bytes = 0   # bytes they handed to jax.device_put
         self.n_gets = 0
         # observability (repro.obs) — attach_obs wires these; null objects
         # keep the resolve hot path branch-free when obs is off
@@ -436,39 +440,49 @@ class ShardedStore:
         and refits only the shards whose memtable actually rolled.  The
         restack/upload still copies every row (O(total records) bytes per
         refresh); updating only the changed device row is the next
-        optimization if flush-heavy workloads make it show up."""
+        optimization if flush-heavy workloads make it show up.  A refresh
+        is the ``repro.device_state`` profiler span, and adds its host
+        time and uploaded bytes to ``state_rebuild_us`` and
+        ``state_upload_bytes``."""
         epochs = self._shard_epochs()
         if self._state is None or epochs != self._state_epochs:
-            fc = self.shards[0].cfg.filters
-            bloom_k = self.shards[0].cfg.lsm.bloom_k
-            for i, st in enumerate(self.shards):
-                if self._snap_epochs[i] != epochs[i]:
-                    self._snaps[i] = merge_live(list(st.tree.all_files()))
-                    self._snap_models[i] = (
-                        greedy_plr_np(self._snaps[i][0], delta=self.delta)
-                        if self._snaps[i][0].shape[0] else None)
-                    # per-shard bloom row, cached under the same epoch:
-                    # the fused GET prunes shards that definitely lack
-                    # the probe before any PLR work
-                    self._snap_filters[i] = (
-                        build_level_filter(self._snaps[i][0],
-                                           fc.bits_per_key, bloom_k)
-                        if fc.enabled and self._snaps[i][0].shape[0]
-                        else None)
-                    self._snap_epochs[i] = epochs[i]
-            state_np = build_dist_state_from_shards(
-                self._snaps, self.delta, models=self._snap_models,
-                filters=self._snap_filters if fc.enabled else None)
-            # on the mesh each shard's row lives on its own device; the
-            # fused one-device program reads every row from one device
-            place = (NamedSharding(self._mesh,
-                                   P(tuple(self._mesh.axis_names)))
-                     if self._mesh is not None else None)
-            self._state = {k: jax.device_put(v, place)
-                           for k, v in state_np.items()}
-            self._state_epochs = epochs
-            self.state_epoch += 1
+            t = time.perf_counter()
+            with TraceAnnotation(SPAN_PREFIX + "device_state"):
+                self._rebuild_state(epochs)
+            self.state_rebuild_us += (time.perf_counter() - t) * 1e6
         return self._state
+
+    def _rebuild_state(self, epochs: tuple) -> None:
+        fc = self.shards[0].cfg.filters
+        bloom_k = self.shards[0].cfg.lsm.bloom_k
+        for i, st in enumerate(self.shards):
+            if self._snap_epochs[i] != epochs[i]:
+                self._snaps[i] = merge_live(list(st.tree.all_files()))
+                self._snap_models[i] = (
+                    greedy_plr_np(self._snaps[i][0], delta=self.delta)
+                    if self._snaps[i][0].shape[0] else None)
+                # per-shard bloom row, cached under the same epoch: the
+                # fused GET prunes shards that definitely lack the probe
+                # before any PLR work
+                self._snap_filters[i] = (
+                    build_level_filter(self._snaps[i][0],
+                                       fc.bits_per_key, bloom_k)
+                    if fc.enabled and self._snaps[i][0].shape[0]
+                    else None)
+                self._snap_epochs[i] = epochs[i]
+        state_np = build_dist_state_from_shards(
+            self._snaps, self.delta, models=self._snap_models,
+            filters=self._snap_filters if fc.enabled else None)
+        # on the mesh each shard's row lives on its own device; the fused
+        # one-device program reads every row from one device
+        place = (NamedSharding(self._mesh, P(tuple(self._mesh.axis_names)))
+                 if self._mesh is not None else None)
+        self._state = {k: jax.device_put(v, place)
+                       for k, v in state_np.items()}
+        self.state_upload_bytes += sum(int(np.asarray(v).nbytes)
+                                       for v in state_np.values())
+        self._state_epochs = epochs
+        self.state_epoch += 1
 
     # ------------------------------------------------------------------ read
     def _dist_dispatch(self, probes: np.ndarray):
@@ -694,10 +708,13 @@ class ShardedStore:
     def _collect_obs(self, reg) -> None:
         reg.counter("fleet_gets_total").observe_total(self.n_gets)
         reg.gauge("fleet_state_epoch").set(self.state_epoch)
+        c = reg.counter
+        c("fleet_state_rebuild_us_total").observe_total(self.state_rebuild_us)
+        c("fleet_state_upload_bytes_total").observe_total(
+            self.state_upload_bytes)
         # value-fetch overlap: fraction of total fetch time that ran
         # concurrently with other work instead of stalling the caller
         # (0.0 when inline; → 1.0 as the pool fully hides the fetch)
-        c = reg.counter
         c("fleet_value_fetch_hidden_us_total").observe_total(
             self._vf_hidden_us)
         c("fleet_value_fetch_exposed_us_total").observe_total(

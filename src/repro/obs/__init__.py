@@ -19,20 +19,22 @@ from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        publish_stats)
 from .trace import (CausalTracer, NullCausalTracer, Span, TraceContext,
                     CRITICAL_STAGES, NULL_CTRACE, SPAN_NAMES)
-from .tracer import (EventLog, NullTracer, StageHandle, StageTracer,
-                     NULL_HANDLE, NULL_TRACER)
+from .tracer import (EventLog, GcPauseHook, NullTracer, StageHandle,
+                     StageTracer, NULL_HANDLE, NULL_TRACER, SPAN_PREFIX)
 
-__all__ = ["CausalTracer", "Counter", "EventLog", "Gauge", "Histogram",
-           "MetricsRegistry", "NullCausalTracer", "NullTracer", "Obs",
-           "ObsConfig", "Span", "StageHandle", "StageTracer", "TraceContext",
-           "CRITICAL_STAGES", "NULL_CTRACE", "NULL_HANDLE", "NULL_TRACER",
-           "SPAN_NAMES", "parse_prometheus", "publish_stats", "to_json",
-           "to_prometheus"]
+__all__ = ["CausalTracer", "Counter", "EventLog", "GcPauseHook", "Gauge",
+           "Histogram", "MetricsRegistry", "NullCausalTracer", "NullTracer",
+           "Obs", "ObsConfig", "Span", "StageHandle", "StageTracer",
+           "TraceContext", "CRITICAL_STAGES", "NULL_CTRACE", "NULL_HANDLE",
+           "NULL_TRACER", "SPAN_NAMES", "SPAN_PREFIX", "parse_prometheus",
+           "publish_stats", "to_json", "to_prometheus"]
 
-# canonical read-path stage names (the §3-style decomposition the serve
-# bench reports); layers pre-bind handles for exactly these
+# canonical stage names: the read path (the §3-style decomposition the
+# serve bench reports), then the rest of the server tick's host work;
+# layers pre-bind handles for exactly these
 READ_STAGES = ("admission", "coalesce", "cache_probe", "filter_probe",
-               "dispatch", "compute", "resolve", "value_fetch")
+               "dispatch", "compute", "resolve", "value_fetch", "retire",
+               "write_apply", "wal_sync", "maintenance", "complete")
 
 
 @dataclasses.dataclass

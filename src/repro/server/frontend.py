@@ -22,17 +22,24 @@ miss), so they are consistent with what the store would answer now.
 from __future__ import annotations
 
 import dataclasses
+import time
+import weakref
 
 import numpy as np
 
 from repro.io import IOPool
-from repro.obs import NULL_CTRACE, NULL_TRACER, Obs, ObsConfig, publish_stats
+from repro.obs import (NULL_CTRACE, NULL_TRACER, GcPauseHook, Obs, ObsConfig,
+                       publish_stats)
 
 from .admission import Batch, Batcher, RequestQueue, ServerRequest
 from .cache import HotKeyCache
 from .coordinator import CoordinatorConfig, FleetMaintenanceCoordinator
 
-__all__ = ["ServerConfig", "BourbonServer"]
+__all__ = ["ServerConfig", "BourbonServer", "STALLED_TICK_S"]
+
+# a tick that takes longer than this counts in server_stalled_ticks_total
+STALLED_TICK_S = 0.050
+_now = time.perf_counter
 
 
 @dataclasses.dataclass
@@ -105,9 +112,27 @@ class BourbonServer:
         self._st_coalesce = tr.stage("coalesce")
         self._st_cache = tr.stage("cache_probe")
         self._st_dispatch = tr.stage("dispatch")
-        self._st_compute = tr.stage("compute")
+        # dispatch->retire crosses ticks: timed, but no profiler span
+        self._st_compute = tr.stage("compute", span=False)
         self._st_resolve = tr.stage("resolve")
+        self._st_retire = tr.stage("retire")
+        self._st_write = tr.stage("write_apply")
+        self._st_wal = tr.stage("wal_sync")
+        self._st_maint = tr.stage("maintenance")
+        self._st_complete = tr.stage("complete")
+        # host effects counted on every tick (plain attributes; the obs
+        # collector folds them into registry counters)
+        self.stalled_ticks = 0       # ticks longer than STALLED_TICK_S
+        self.wal_sync_us = 0.0       # host time of the durability barrier
+        self.wal_syncs = 0
+        self.bubble_us = 0.0         # host time of maintenance rounds
+        self._gc = None
+        self._gc_off = None
         if self.obs is not None:
+            # the collector's pauses, timed where they happen; the
+            # finalizer unhooks it should shutdown() never be called
+            self._gc = GcPauseHook()
+            self._gc_off = weakref.finalize(self, self._gc.remove)
             store.attach_obs(self.obs)
             self.obs.registry.register_collector("server",
                                                  self._collect_obs)
@@ -122,8 +147,11 @@ class BourbonServer:
 
     def shutdown(self) -> None:
         """Release the host I/O plane: detach the fleet and stop the pool
-        workers.  Idempotent; the store itself stays open (a closed pool
-        would run any straggler inline, so this is always safe)."""
+        workers, and remove the collector hook.  Idempotent; the store
+        itself stays open (a closed pool would run any straggler inline,
+        so this is always safe)."""
+        if self._gc_off is not None:
+            self._gc_off()
         if self.io is not None:
             self.store.detach_io()
             self.io.close()
@@ -146,6 +174,7 @@ class BourbonServer:
         """One server iteration: drain up to ``max_batches_per_tick``
         coalesced batches, then run one maintenance-coordination round.
         Returns the requests completed this tick."""
+        t_tick = _now()
         done: list[ServerRequest] = []
         tick_no = self._tr.begin_tick()
         wrote = False
@@ -162,14 +191,7 @@ class BourbonServer:
                 wrote = True
             done.extend(batch.requests)
         if wrote:
-            # durability barrier before acknowledging: all write batches
-            # applied this tick coalesce into ONE group-commit sync per
-            # shard (no-op under the per-append writer) — the WAL commit
-            # contract's sync point
-            wsp = self._ct.begin_span("wal_sync", self._wal_parent)
-            self.store.wal_sync()
-            self._ct.end_span(wsp)
-            self._wal_parent = None
+            self._wal_sync()
         if not done:
             # an idle tick is still the passage of (virtual) time: advance
             # the shard clocks so T_waits (learning and GC candidacy)
@@ -181,24 +203,59 @@ class BourbonServer:
         # the shards self-drive GC/checkpointing) under any load shape —
         # _maintenance_tick no-ops on deferred shards, so this never
         # bypasses the coordinator's budget
-        msp = self._ct.begin_maintenance(self.ticks, kind="tick")
+        self._maintenance("tick")
+        m = self.store.maintenance_us()
+        self.max_maintenance_tick_us = max(self.max_maintenance_tick_us,
+                                           m - self._maint_us_seen)
+        self._maint_us_seen = m
+        self._complete(done)
+        self._end_tick(tick_no, t_tick)
+        return done
+
+    def _wal_sync(self) -> None:
+        """Durability barrier before acknowledging: every write batch
+        applied this tick becomes durable under ONE coalesced
+        group-commit sync per shard (a no-op per-append writer makes this
+        free) — the WAL commit contract's sync point."""
+        t = _now()
+        t0 = self._st_wal.begin()
+        wsp = self._ct.begin_span("wal_sync", self._wal_parent)
+        self.store.wal_sync()
+        self._ct.end_span(wsp)
+        self._wal_parent = None
+        self._st_wal.end(t0)
+        self.wal_sync_us += (_now() - t) * 1e6
+        self.wal_syncs += 1
+
+    def _maintenance(self, kind: str) -> None:
+        """One maintenance round: every store's own tick (learning, and
+        GC/checkpointing where no coordinator owns them), then one
+        coordinator round."""
+        t = _now()
+        t0 = self._st_maint.begin()
+        msp = self._ct.begin_maintenance(self.ticks, kind=kind)
         for sh in self.store.shards:
             sh._tick()
         if self.coordinator is not None:
             self.coordinator.tick()
         self._ct.end_maintenance(msp)
-        m = self.store.maintenance_us()
-        self.max_maintenance_tick_us = max(self.max_maintenance_tick_us,
-                                           m - self._maint_us_seen)
-        self._maint_us_seen = m
+        self._st_maint.end(t0)
+        self.bubble_us += (_now() - t) * 1e6
+
+    def _complete(self, done: list[ServerRequest]) -> None:
+        t0 = self._st_complete.begin()
         for r in done:
             r.completed_tick = self.ticks
             r.done = True
             self._ct.complete(r.trace, tick=self.ticks)
         self.completed += len(done)
+        self._st_complete.end(t0)
+
+    def _end_tick(self, tick_no: int, t_tick: float) -> None:
         self._tr.end_tick(tick_no)
         self.ticks += 1
-        return done
+        if _now() - t_tick > STALLED_TICK_S:
+            self.stalled_ticks += 1
 
     def run_until_drained(self, max_ticks: int = 100000
                           ) -> list[ServerRequest]:
@@ -229,6 +286,7 @@ class BourbonServer:
             hit = np.zeros(uniq.shape[0], bool)
             epochs = None                  # no cache: _fill_cache no-ops
         miss = ~hit
+        f = None
         if miss.any():
             # the synchronous path still splits dispatch from resolve so
             # the stage breakdown is comparable with the pipelined
@@ -249,6 +307,8 @@ class BourbonServer:
             self._st_resolve.end(t0)
             self._ct.end_span(csp, stage="device_compute")
             self._st_compute.end(tc)
+        t0 = self._st_retire.begin()
+        if f is not None:
             found[miss] = f
             vals[miss] = v
             self.store_probe_keys += int(miss.sum())
@@ -258,6 +318,7 @@ class BourbonServer:
         for req, idx in zip(batch.requests, batch.scatter):
             req.found = found[idx]
             req.result = vals[idx]
+        self._st_retire.end(t0)
         self._ct.end_span(bt)
 
     def _charge_read_clocks(self, owners_probed: np.ndarray) -> None:
@@ -278,6 +339,7 @@ class BourbonServer:
 
     # ---------------------------------------------------------------- writes
     def _apply_writes(self, batch: Batch) -> None:
+        t0 = self._st_write.begin()
         bt = self._ct.join_batch(batch.requests, kind="write")
         # arm the ambient write span: WAL appends issued while applying
         # this batch parent under it (ended by the commit group's fsync)
@@ -292,6 +354,7 @@ class BourbonServer:
         self._ct.end_span(bt)
         if bt is not None:
             self._wal_parent = bt
+        self._st_write.end(t0)
 
     # ------------------------------------------------------------------- obs
     def _collect_obs(self, reg) -> None:
@@ -309,6 +372,14 @@ class BourbonServer:
             self.served_from_cache)
         c("server_store_probe_keys_total").observe_total(
             self.store_probe_keys)
+        c("server_stalled_ticks_total").observe_total(self.stalled_ticks)
+        c("server_wal_sync_us_total").observe_total(self.wal_sync_us)
+        c("server_wal_syncs_total").observe_total(self.wal_syncs)
+        c("server_bubble_us_total").observe_total(self.bubble_us)
+        c("server_gc_pause_us_total").observe_total(self._gc.pause_us)
+        for gen, n in enumerate(self._gc.collections):
+            c("server_gc_collections_total",
+              generation=str(gen)).observe_total(n)
         if self.cache is not None:
             cs = self.cache.stats()
             for k in ("hits", "misses", "fills", "evictions",

@@ -45,7 +45,7 @@ from collections import deque
 import numpy as np
 
 from .admission import Batch, ServerRequest
-from .frontend import BourbonServer, ServerConfig
+from .frontend import BourbonServer, ServerConfig, _now
 
 __all__ = ["PipelineConfig", "PipelinedServer"]
 
@@ -129,6 +129,7 @@ class PipelinedServer(BourbonServer):
         finished — resolving only after all of this tick's admission work
         has been overlapped with the device compute.  Returns the
         requests completed this tick."""
+        t_tick = _now()
         done: list[ServerRequest] = []
         tick_no = self._tr.begin_tick()
         # prefetch the blocking halves: every batch already in flight had
@@ -195,21 +196,9 @@ class PipelinedServer(BourbonServer):
                                            m - self._maint_us_seen)
         self._maint_us_seen = m
         if wrote:
-            # durability barrier before acknowledging: every write batch
-            # this tick applied becomes durable under ONE coalesced
-            # group-commit sync per shard (a no-op per-append writer makes
-            # this free) — the WAL commit contract's sync point
-            wsp = self._ct.begin_span("wal_sync", self._wal_parent)
-            self.store.wal_sync()
-            self._ct.end_span(wsp)
-            self._wal_parent = None
-        for r in done:
-            r.completed_tick = self.ticks
-            r.done = True
-            self._ct.complete(r.trace, tick=self.ticks)
-        self.completed += len(done)
-        self._tr.end_tick(tick_no)
-        self.ticks += 1
+            self._wal_sync()
+        self._complete(done)
+        self._end_tick(tick_no, t_tick)
         return done
 
     def run_until_drained(self, max_ticks: int = 100000
@@ -298,6 +287,8 @@ class PipelinedServer(BourbonServer):
                                   link=fl.fetch.span)
         f, v = fl.fetch.wait()
         self._ct.end_span(vsp, stage="value_fetch")
+        # the host tail after the join: merge, clocks, cache fill, scatter
+        t0 = self._st_retire.begin()
         fl.found[fl.miss] = f
         fl.vals[fl.miss] = v
         self.store_probe_keys += int(fl.miss.sum())
@@ -309,8 +300,10 @@ class PipelinedServer(BourbonServer):
                          fl.pending.epochs)
         self.batches_retired += 1
         self._ct.end_span(fl.tr_batch)
-        return self._scatter(fl.batch, fl.found, fl.vals,
+        done = self._scatter(fl.batch, fl.found, fl.vals,
                              epochs=fl.pending.epochs)
+        self._st_retire.end(t0)
+        return done
 
     def _retire(self, fl: _InflightRead) -> list[ServerRequest]:
         """Resolve one in-flight batch and fan the results back out."""
@@ -356,12 +349,7 @@ class PipelinedServer(BourbonServer):
                >= self.cfg.bubble_every_ticks)
         if not due:
             return
-        msp = self._ct.begin_maintenance(self.ticks, kind="bubble")
-        for sh in self.store.shards:
-            sh._tick()
-        if self.coordinator is not None:
-            self.coordinator.tick()
-        self._ct.end_maintenance(msp)
+        self._maintenance("bubble")
         self._last_bubble = self.ticks
         self.bubbles += 1
 

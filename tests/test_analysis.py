@@ -334,6 +334,23 @@ def test_obsdrift_reads_live_declarations():
     assert rule._trace_drift == []
 
 
+def test_obsdrift_compares_readme_stage_table(tmp_path):
+    """The README's stage table is parsed (the table follows its heading
+    after a blank line) and checked against READ_STAGES both ways."""
+    readme = os.path.join(REPO, "src", "repro", "obs", "README.md")
+    init = os.path.join(REPO, "src", "repro", "obs", "__init__.py")
+    rule = ObsDriftRule(obs_init=init, obs_readme=readme)
+    assert rule._stage_drift is None
+    assert "retire" in rule.stages and "generation" in rule.labels
+    with open(readme, encoding="utf-8") as f:
+        text = f.read()
+    bad = tmp_path / "README.md"
+    bad.write_text(text.replace("| `complete`", "| `completion`"))
+    rule = ObsDriftRule(obs_init=init, obs_readme=str(bad))
+    _, code, table = rule._stage_drift
+    assert "complete" in code and "completion" in table
+
+
 # ------------------------------------------------------------- suppressions
 
 def test_suppression_honored(tmp_path):

@@ -2,10 +2,15 @@
 stage tracer sampling, the lazy per-level probe-split (no extra blocking
 device transfers on the read hot path), counter monotonicity across
 epoch events (memtable roll, compaction, store reopen), the per-shard
-labeled stats breakdown, and the served-from-cache reconciliation
-through ``PipelinedServer`` snapshots."""
+labeled stats breakdown, the served-from-cache reconciliation through
+``PipelinedServer`` snapshots, the stage spans on a recorded CPU
+profile, and the server's host-effect counters (collector pauses,
+stalled ticks, WAL syncs, maintenance rounds, state rebuilds)."""
 
+import gc
 import json
+import time
+import tracemalloc
 
 import numpy as np
 import pytest
@@ -15,11 +20,13 @@ from repro.core.engine import EngineConfig, LookupResult
 from repro.core.lsm import N_LEVELS
 from repro.core.store import BourbonStore
 from repro.distributed import ShardedConfig, ShardedStore
-from repro.obs import (EventLog, MetricsRegistry, NULL_TRACER, Obs,
-                       ObsConfig, READ_STAGES, StageTracer, parse_prometheus,
-                       publish_stats, to_json, to_prometheus)
-from repro.server import (PipelineConfig, PipelinedServer, ServerConfig,
-                          ServerRequest)
+import repro.obs.tracer as obs_tracer
+from repro.obs import (EventLog, GcPauseHook, MetricsRegistry, NULL_TRACER,
+                       Obs, ObsConfig, READ_STAGES, SPAN_PREFIX, StageTracer,
+                       parse_prometheus, publish_stats, to_json,
+                       to_prometheus)
+from repro.server import (BourbonServer, PipelineConfig, PipelinedServer,
+                          ServerConfig, ServerRequest)
 
 VALUE_SIZE = 16
 
@@ -591,4 +598,264 @@ def test_sync_server_snapshot_has_stages(tmp_path):
     assert all(stages.get(name, 0) > 0 for name in READ_STAGES), stages
     tl = srv.obs.timeline()
     assert tl and all("tick" in row for row in tl)
+    st.close()
+
+
+# ------------------------------------------------------- profiler spans
+
+# the stages that name the tick's host work beyond the read path
+TICK_STAGES = ("retire", "write_apply", "wal_sync", "maintenance",
+               "complete")
+
+
+def _profile(tmp_path, fn):
+    """Run ``fn`` under a CPU profiler session; return the ``repro.*``
+    host events as (line index, name, start_ns, end_ns), where the line
+    index is the event's thread within its plane."""
+    import jax
+    out = tmp_path / "prof"
+    jax.profiler.start_trace(str(out))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (xp,) = out.glob("plugins/profile/*/*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(xp))
+    evs = []
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            evs.extend((i, ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                       for ev in line.events
+                       if ev.name.startswith(SPAN_PREFIX))
+    return evs
+
+
+def _assert_nested(spans):
+    """Spans of one thread open and close like a stack."""
+    stack = []
+    for name, s, e in sorted(spans, key=lambda x: (x[1], -x[2])):
+        while stack and s >= stack[-1][2]:
+            stack.pop()
+        if stack:
+            assert e <= stack[-1][2], (name, s, e, stack[-1])
+        stack.append((name, s, e))
+
+
+def _mixed_ticks(srv, keys, rid0, ticks=8):
+    """Each tick: one single-key put, then a read batch of keys no
+    earlier call read (so they reach the store); the last tick idles so
+    a maintenance round runs."""
+    for i in range(ticks):
+        k = keys[i: i + 1]
+        assert srv.submit(ServerRequest(rid0 + 2 * i, "put", k,
+                                        _values(k, 1)))
+        srv.tick()
+        lo = 100 + rid0 + 8 * i
+        assert srv.submit(ServerRequest(rid0 + 2 * i + 1, "get",
+                                        keys[lo: lo + 8]))
+        srv.tick()
+    srv.run_until_drained()
+    srv.tick()
+
+
+@pytest.mark.parametrize("server", ["pipelined", "sync"])
+def test_stage_spans_on_profiler_trace(tmp_path, server):
+    keys = _keys(3000, seed=20)
+    st = _sharded(tmp_path, keys, n_shards=2, fetch_values=True)
+    _fill(st, keys)
+    st.flush_all()
+    cls, cfg = ((PipelinedServer, PipelineConfig) if server == "pipelined"
+                else (BourbonServer, ServerConfig))
+    srv = cls(st, cfg(max_wait_ticks=0, obs=ObsConfig(sample_every=4)))
+    _mixed_ticks(srv, keys, 0, ticks=2)          # warm every path
+    t0 = srv.ticks
+    evs = _profile(tmp_path, lambda: _mixed_ticks(srv, keys, 100))
+    ticks = srv.ticks - t0
+    names = {n for _, n, _, _ in evs}
+    want = {SPAN_PREFIX + s for s in TICK_STAGES + (
+        "admission", "coalesce", "cache_probe", "dispatch", "resolve",
+        "value_fetch")}
+    assert want <= names, sorted(want - names)
+    assert SPAN_PREFIX + "compute" not in names  # crosses ticks: no span
+    # one completion span a tick: spans are written on every tick, not
+    # only on the sampled quarter
+    assert sum(n == SPAN_PREFIX + "complete" for _, n, _, _ in evs) == ticks
+    tick_line = {ln for ln, n, _, _ in evs if n == SPAN_PREFIX + "coalesce"}
+    assert len(tick_line) == 1                   # all on the tick thread
+    (ln,) = tick_line
+    _assert_nested([(n, s, e) for i, n, s, e in evs
+                    if i == ln and n != SPAN_PREFIX + "gc"])
+    # every handle closed what it opened
+    assert all(not h._open for h in srv._tr._stages.values())
+    srv.shutdown()
+    st.close()
+
+
+def test_no_spans_written_without_a_profile(tmp_path, monkeypatch):
+    made = []
+
+    class Recording:
+        def __init__(self, name):
+            made.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(obs_tracer, "TraceAnnotation", Recording)
+    keys = _keys(3000, seed=21)
+    st = _sharded(tmp_path, keys, n_shards=2, fetch_values=True)
+    _fill(st, keys)
+    srv = PipelinedServer(st, PipelineConfig(max_wait_ticks=0))
+    gc.collect()
+    _mixed_ticks(srv, keys, 0, ticks=3)
+    assert made == []                            # no session recording
+    monkeypatch.setattr(obs_tracer, "profiling", lambda: True)
+    _mixed_ticks(srv, keys, 100, ticks=3)
+    assert {SPAN_PREFIX + s for s in TICK_STAGES} <= set(made)
+    srv.shutdown()
+    st.close()
+
+
+def test_unprofiled_stage_calls_allocate_nothing():
+    tr = StageTracer(MetricsRegistry(), sample_every=1 << 30)
+    h = tr.stage("dispatch")
+    tr.begin_tick()
+    tr.begin_tick()                              # an unsampled tick
+    assert tr._prof is False
+    h.end(h.begin())
+    tracemalloc.start()
+    try:
+        before = tracemalloc.take_snapshot()
+        for _ in range(2000):
+            h.end(h.begin())
+        after = tracemalloc.take_snapshot()
+    finally:
+        tracemalloc.stop()
+    grown = [d for d in after.compare_to(before, "filename")
+             if d.traceback[0].filename == obs_tracer.__file__]
+    assert sum(d.count_diff for d in grown) == 0, grown
+    assert h._open == []
+
+
+def test_obs_off_server_writes_no_spans(tmp_path):
+    keys = _keys(3000, seed=22)
+    st = _sharded(tmp_path, keys, n_shards=2, fetch_values=True)
+    _fill(st, keys)
+    st.device_state()
+    srv = PipelinedServer(st, PipelineConfig(
+        max_wait_ticks=0, obs=ObsConfig(enabled=False)))
+    assert srv._gc is None
+    gc.collect()                 # finalize servers earlier tests left
+    assert not any(isinstance(cb, GcPauseHook) for cb in gc.callbacks)
+    _mixed_ticks(srv, keys, 0, ticks=2)
+    evs = _profile(tmp_path, lambda: _mixed_ticks(srv, keys, 100, ticks=4))
+    assert evs == []
+    st.close()
+
+
+# ----------------------------------------------------- host-effect counters
+
+def _counter(srv, name, **labels):
+    return _sample(srv.obs.snapshot(), name, **labels)
+
+
+def test_gc_pause_counters_and_hook_lifetime(tmp_path):
+    keys = _keys(2000, seed=23)
+    st = _sharded(tmp_path, keys, n_shards=2, fetch_values=True)
+    srv = PipelinedServer(st, PipelineConfig(max_wait_ticks=0))
+    hook = srv._gc
+    assert hook in gc.callbacks
+    us0 = _counter(srv, "server_gc_pause_us_total")
+    n0 = _counter(srv, "server_gc_collections_total", generation="2")
+    gc.collect()
+    assert _counter(srv, "server_gc_pause_us_total") > us0
+    assert _counter(srv, "server_gc_collections_total",
+                    generation="2") >= n0 + 1
+    srv.shutdown()
+    assert hook not in gc.callbacks
+    srv.shutdown()                               # idempotent
+    # a server that is never shut down unhooks when it is collected
+    srv2 = PipelinedServer(st, PipelineConfig(max_wait_ticks=0))
+    hook2 = srv2._gc
+    st.detach_obs()                              # the store let go of it
+    del srv2
+    gc.collect()
+    assert hook2 not in gc.callbacks
+    st.close()
+
+
+def test_stalled_ticks_and_wal_sync_counters(tmp_path):
+    keys = _keys(2000, seed=24)
+    st = _sharded(tmp_path, keys, n_shards=2, fetch_values=True)
+    _fill(st, keys)
+    srv = PipelinedServer(st, PipelineConfig(max_wait_ticks=0))
+    for i in range(3):                           # one write per tick
+        k = keys[i: i + 1]
+        assert srv.submit(ServerRequest(i, "put", k, _values(k, 1)))
+        srv.tick()
+    assert _counter(srv, "server_wal_syncs_total") == 3
+    assert _counter(srv, "server_stalled_ticks_total") == 0
+    us = _counter(srv, "server_wal_sync_us_total")
+    assert us > 0
+    sync = st.wal_sync
+
+    def slow_sync():                             # a stalled barrier
+        time.sleep(0.06)
+        sync()
+
+    st.wal_sync = slow_sync
+    k = keys[10:11]
+    assert srv.submit(ServerRequest(10, "put", k, _values(k, 2)))
+    srv.tick()
+    assert _counter(srv, "server_stalled_ticks_total") == 1
+    assert _counter(srv, "server_wal_syncs_total") == 4
+    assert _counter(srv, "server_wal_sync_us_total") - us >= 60_000
+    # a read-only tick syncs nothing
+    assert srv.submit(ServerRequest(11, "get", keys[:8]))
+    srv.run_until_drained()
+    assert _counter(srv, "server_wal_syncs_total") == 4
+    srv.shutdown()
+    st.close()
+
+
+def test_bubble_counter_times_maintenance_rounds(tmp_path):
+    keys = _keys(2000, seed=25)
+    st = _sharded(tmp_path, keys, n_shards=2, fetch_values=True)
+    _fill(st, keys)
+    srv = PipelinedServer(st, PipelineConfig(max_wait_ticks=0))
+    assert _counter(srv, "server_bubble_us_total") == 0
+    srv.tick()                                   # idle: a bubble runs
+    assert srv.bubbles == 1
+    assert _counter(srv, "server_bubble_us_total") > 0
+    srv.shutdown()
+    st.close()
+
+
+def test_device_state_rebuild_counters(tmp_path):
+    keys = _keys(3000, seed=26)
+    st = _sharded(tmp_path, keys, n_shards=2)
+    st.attach_obs(Obs())
+    _fill(st, keys)
+    state = st.device_state()
+    nbytes = sum(int(np.asarray(v).nbytes) for v in state.values())
+    snap = st._obs.snapshot()
+    assert _sample(snap, "fleet_state_upload_bytes_total") == nbytes
+    us = _sample(snap, "fleet_state_rebuild_us_total")
+    assert us > 0
+    st.device_state()                            # cached: no rebuild
+    snap = st._obs.snapshot()
+    assert _sample(snap, "fleet_state_upload_bytes_total") == nbytes
+    assert _sample(snap, "fleet_state_rebuild_us_total") == us
+    st.put_batch(keys[:16] + 1)
+    st.flush_all()
+    state = st.device_state()                    # a roll: rebuilt
+    snap = st._obs.snapshot()
+    assert _sample(snap, "fleet_state_upload_bytes_total") == nbytes + sum(
+        int(np.asarray(v).nbytes) for v in state.values())
+    assert _sample(snap, "fleet_state_rebuild_us_total") > us
     st.close()
