@@ -126,6 +126,7 @@ class BourbonServer:
         self.wal_sync_us = 0.0       # host time of the durability barrier
         self.wal_syncs = 0
         self.bubble_us = 0.0         # host time of maintenance rounds
+        self.cache_host_us = 0.0     # host time inside HotKeyCache calls
         self._gc = None
         self._gc_off = None
         if self.obs is not None:
@@ -278,7 +279,9 @@ class BourbonServer:
             # probe and the fill below
             epochs = self.store.shard_epochs()
             t0 = self._st_cache.begin()
+            t = _now()
             hit = self.cache.lookup(uniq, epochs, vals)
+            self.cache_host_us += (_now() - t) * 1e6
             self._st_cache.end(t0)
             found |= hit
             self.served_from_cache += int(hit.sum())
@@ -335,7 +338,10 @@ class BourbonServer:
                     epochs: tuple) -> None:
         """Admit found keys read under ``epochs`` into the HotKeyCache."""
         if self.cache is not None and keys.shape[0]:
-            self.cache.fill(keys, vals, self.store.shard_of(keys), epochs)
+            owners = self.store.shard_of(keys)
+            t = _now()
+            self.cache.fill(keys, vals, owners, epochs)
+            self.cache_host_us += (_now() - t) * 1e6
 
     # ---------------------------------------------------------------- writes
     def _apply_writes(self, batch: Batch) -> None:
@@ -350,7 +356,9 @@ class BourbonServer:
             self.store.delete_batch(batch.keys)
         self._ct.set_write(None)
         if self.cache is not None:
+            t = _now()
             self.cache.invalidate(batch.keys)
+            self.cache_host_us += (_now() - t) * 1e6
         self._ct.end_span(bt)
         if bt is not None:
             self._wal_parent = bt
@@ -385,6 +393,7 @@ class BourbonServer:
             for k in ("hits", "misses", "fills", "evictions",
                       "inval_epoch", "inval_write"):
                 c(f"cache_{k}_total").observe_total(cs[k])
+            c("server_cache_host_us_total").observe_total(self.cache_host_us)
         s = {k: v for k, v in self.stats().items() if k != "store"}
         publish_stats(reg, "server", s)
 

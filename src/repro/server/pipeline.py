@@ -220,8 +220,11 @@ class PipelinedServer(BourbonServer):
         vals = np.zeros((uniq.shape[0], self._value_size), np.uint8)
         found = np.zeros(uniq.shape[0], bool)
         if self.cache is not None:
+            epochs = self.store.shard_epochs()
             t0 = self._st_cache.begin()
-            hit = self.cache.lookup(uniq, self.store.shard_epochs(), vals)
+            t = _now()
+            hit = self.cache.lookup(uniq, epochs, vals)
+            self.cache_host_us += (_now() - t) * 1e6
             self._st_cache.end(t0)
             found |= hit
             self.served_from_cache += int(hit.sum())

@@ -836,6 +836,29 @@ def test_bubble_counter_times_maintenance_rounds(tmp_path):
     st.close()
 
 
+def test_cache_host_counter_times_probe_fill_and_invalidation(tmp_path):
+    keys = _keys(2000, seed=27)
+    st = _sharded(tmp_path, keys, n_shards=2, fetch_values=True)
+    _fill(st, keys)
+    srv = PipelinedServer(st, PipelineConfig(max_wait_ticks=0))
+    assert _counter(srv, "server_cache_host_us_total") == 0
+    assert srv.submit(ServerRequest(0, "get", keys[:8]))
+    srv.run_until_drained()                      # probe, then fill
+    us = _counter(srv, "server_cache_host_us_total")
+    assert us > 0
+    k = keys[:1]
+    assert srv.submit(ServerRequest(1, "put", k, _values(k, 1)))
+    srv.tick()                                   # write invalidation
+    assert _counter(srv, "server_cache_host_us_total") > us
+    srv.shutdown()
+    # a server without a cache exports no cache time
+    srv2 = PipelinedServer(st, PipelineConfig(max_wait_ticks=0,
+                                              cache_slots=0))
+    assert "server_cache_host_us_total" not in srv2.obs.snapshot()
+    srv2.shutdown()
+    st.close()
+
+
 def test_device_state_rebuild_counters(tmp_path):
     keys = _keys(3000, seed=26)
     st = _sharded(tmp_path, keys, n_shards=2)

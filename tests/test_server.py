@@ -456,6 +456,197 @@ def test_cache_fill_larger_than_slots_keeps_tail_and_counts_evictions():
     assert hit.all() and (out[:, 0] == keys[-8:]).all()
 
 
+class _CacheModel:
+    """Reference for HotKeyCache: a dict of key -> (value, shard, epoch,
+    stamp) under the same batch clock.  Which of two equal-stamped keys a
+    fill evicts is left to the cache; the model checks the choice."""
+
+    def __init__(self, slots):
+        self.slots = slots
+        self.entries = {}
+        self.clock = 0
+        self.filled = False
+        self.counts = dict(hits=0, misses=0, fills=0, evictions=0,
+                           inval_epoch=0, inval_write=0)
+
+    def lookup(self, keys, epochs):
+        """Expected (hit mask, values of the hits)."""
+        hit = np.zeros(keys.shape[0], bool)
+        vals = {}
+        if not self.filled:
+            self.counts["misses"] += keys.shape[0]
+            return hit, vals
+        found = [k for k in keys.tolist() if k in self.entries]
+        fresh = []
+        for k in found:
+            val, shard, epoch, _ = self.entries[k]
+            if epoch == epochs[shard]:
+                fresh.append(k)
+                vals[k] = val
+            else:
+                del self.entries[k]
+                self.counts["inval_epoch"] += 1
+        if found:
+            self.clock += 1
+            for k in fresh:
+                val, shard, epoch, _ = self.entries[k]
+                self.entries[k] = (val, shard, epoch, self.clock)
+        hit[np.isin(keys, np.array(fresh, np.int64))] = True
+        self.counts["hits"] += len(fresh)
+        self.counts["misses"] += keys.shape[0] - len(fresh)
+        return hit, vals
+
+    def fill(self, keys, values, owners, epochs, cached):
+        """Apply a fill; ``cached`` is the set of keys the cache holds
+        after it, from which the evicted keys are read and checked."""
+        if keys.shape[0] > self.slots:
+            drop = keys.shape[0] - self.slots
+            self.counts["evictions"] += drop
+            self.counts["fills"] += drop
+            keys, values, owners = (keys[-self.slots:],
+                                    values[-self.slots:],
+                                    owners[-self.slots:])
+        self.filled = True
+        self.clock += 1
+        klist = keys.tolist()
+        updating = {k for k in klist if k in self.entries}
+        n_new = len(klist) - len(updating)
+        need = max(n_new - (self.slots - len(self.entries)), 0)
+        evicted = [k for k in self.entries
+                   if k not in updating and k not in cached]
+        assert len(evicted) == need
+        survivors = [st for k, (_, _, _, st) in self.entries.items()
+                     if k not in updating and k in cached]
+        if evicted and survivors:
+            # batch-granular LRU: nothing evicted was newer than a survivor
+            assert (max(self.entries[k][3] for k in evicted)
+                    <= min(survivors))
+        for k in evicted:
+            del self.entries[k]
+        for i, k in enumerate(klist):
+            o = int(owners[i])
+            self.entries[k] = (values[i].tobytes(), o, epochs[o],
+                               self.clock)
+        self.counts["evictions"] += need
+        self.counts["fills"] += len(klist)
+        assert set(klist) <= cached          # every found miss admitted
+
+    def invalidate(self, keys):
+        n = 0
+        for k in np.unique(keys).tolist():
+            if self.entries.pop(k, None) is not None:
+                n += 1
+        self.counts["inval_write"] += n
+        return n
+
+
+def _cached_keys(c):
+    """The keys a HotKeyCache holds, read from its index, after checking
+    the index against the row storage: ascending keys, the live ones
+    (row >= 0) each first among its equals and beside the row that holds
+    it, and every live row indexed once.  Released entries are dropped
+    often enough that the index holds at most the keys live at the last
+    merge plus the eighth of the slots it may leave released."""
+    ik, ir = c._ikeys, c._irows
+    assert ik.shape[0] <= c.slots + c.slots // 8
+    assert (np.diff(ik) >= 0).all()
+    live = ir >= 0
+    first = np.r_[True, ik[1:] != ik[:-1]]
+    assert (first | ~live).all()
+    ik, ir = ik[live], ir[live]
+    assert (c._key[ir] == ik).all()
+    assert np.array_equal(np.sort(ir), np.flatnonzero(c._key >= 0))
+    assert len(c) == ik.shape[0]
+    return set(ik.tolist())
+
+
+@pytest.mark.parametrize("slots", [4, 64, 4096])
+@pytest.mark.parametrize("batch", [1, 2, 1024, "over"])
+def test_cache_matches_dict_reference_model(slots, batch):
+    """Random lookups, fills (some under a stale epoch vector),
+    invalidations and epoch bumps against the dict-plus-stamp model:
+    every hit serves that key's latest fill under a fresh epoch, nothing
+    is served after an invalidate or an epoch move, the cache never
+    outgrows its slots, evictions take the oldest stamps, and stats()
+    counts what the model counts."""
+    from repro.server import HotKeyCache
+    b = slots + slots // 2 + 1 if batch == "over" else batch
+    rng = np.random.default_rng(slots * 7919 + b)
+    universe = 2 * max(slots, b)
+    weights = 1.0 / np.arange(1, universe + 1) ** 0.8
+    weights /= weights.sum()
+    c, model = HotKeyCache(slots), _CacheModel(slots)
+    epochs, prev = [0, 0, 0], (0, 0, 0)
+    # start full, of keys the ops never draw: small batches evict too
+    cold = np.arange(slots, dtype=np.int64) * 3 + 2
+    cold_vals = np.zeros((slots, 16), np.uint8)
+    c.fill(cold, cold_vals, cold % 3, tuple(epochs))
+    model.fill(cold, cold_vals, cold % 3, tuple(epochs), _cached_keys(c))
+    version = 0
+    n_ops = 60 if b >= 1024 else 300
+    for _ in range(n_ops):
+        op = rng.random()
+        keys = rng.choice(universe, b, replace=False, p=weights).astype(
+            np.int64) * 3 + 1
+        if op < 0.4:
+            out = np.zeros((b, 16), np.uint8)
+            hit = c.lookup(keys, tuple(epochs), out)
+            want, vals = model.lookup(keys, tuple(epochs))
+            assert np.array_equal(hit, want)
+            for i in np.flatnonzero(hit):
+                assert out[i].tobytes() == vals[int(keys[i])]
+        elif op < 0.75:
+            version += 1
+            values = np.stack([keys, np.full(b, version)], 1).view(
+                np.uint8).reshape(b, 16)
+            owners = keys % 3
+            ep = prev if rng.random() < 0.1 else tuple(epochs)
+            c.fill(keys, values, owners, ep)
+            model.fill(keys, values, owners, ep, _cached_keys(c))
+        elif op < 0.9:
+            assert c.invalidate(keys) == model.invalidate(keys)
+        else:
+            prev = tuple(epochs)
+            epochs[int(rng.integers(3))] += 1
+        assert len(c) == len(model.entries) <= slots
+        assert _cached_keys(c) == set(model.entries)
+        st = c.stats()
+        assert {k: st[k] for k in model.counts} == model.counts
+    assert model.counts["hits"] > 0 and model.counts["evictions"] > 0
+
+
+def test_cache_reuses_freed_rows():
+    """Rows freed by invalidation and by stale-epoch drops are reused: free
+    rows plus entries always make up the slots, and a fill that fits in
+    the freed rows evicts nothing."""
+    from repro.server import HotKeyCache
+    slots = 64
+    c = HotKeyCache(slots)
+    rng = np.random.default_rng(3)
+    epochs = [0, 0]
+    for step in range(200):
+        keys = rng.choice(1000, 16, replace=False).astype(np.int64)
+        vals = np.zeros((16, 8), np.uint8)
+        c.fill(keys, vals, keys % 2, tuple(epochs))
+        if step % 3 == 0:
+            c.invalidate(keys[:5])
+        if step % 7 == 0:
+            epochs[step % 2] += 1
+            c.lookup(np.arange(1000, dtype=np.int64), tuple(epochs),
+                     np.zeros((1000, 8), np.uint8))
+        assert np.count_nonzero(c._key < 0) + len(c) == slots
+    assert c.inval_epoch > 0 and c.inval_write > 0
+    c.invalidate(np.arange(1000, dtype=np.int64)[::2])
+    free = int(np.count_nonzero(c._key < 0))
+    assert free > 0
+    ev, n0 = c.evictions, len(c)
+    keys = np.arange(5000, 5000 + free, dtype=np.int64)
+    c.fill(keys, np.zeros((free, 8), np.uint8), keys % 2, tuple(epochs))
+    assert c.evictions == ev and len(c) == n0 + free == slots
+    out = np.zeros((free, 8), np.uint8)
+    assert c.lookup(keys, tuple(epochs), out).all()
+
+
 # ------------------------------------------------- ShardedStore satellites
 
 def test_sharded_range_query_merges_across_shard_boundaries(tmp_path):
